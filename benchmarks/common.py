@@ -4,11 +4,34 @@ the sweep-scale CLI flags every fused jax benchmark shares."""
 from __future__ import annotations
 
 import json
+import os
 import time
 from pathlib import Path
-from typing import Callable, Union
+from typing import Callable, Optional, Union
 
 RESULTS = Path(__file__).resolve().parent / "results"
+#: the checkout's fixed compile-cache directory (JAX keys cache entries
+#: by path, so a directory that moves between runs never hits)
+JAX_CACHE = Path(__file__).resolve().parent.parent / ".jax_cache"
+
+
+def enable_compile_cache() -> Optional[str]:
+    """Turn on JAX's persistent compile cache for an entry point.
+
+    When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here; otherwise the cache goes to the checkout's
+    ``.jax_cache/``.  Returns the directory in use, or None on a host
+    without jax.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    try:
+        import jax
+    except ImportError:
+        return None
+    jax.config.update("jax_compilation_cache_dir", str(JAX_CACHE))
+    return str(JAX_CACHE)
 
 
 def add_sweep_args(ap, *, quick: bool = False) -> None:

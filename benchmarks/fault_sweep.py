@@ -40,7 +40,13 @@ import math
 
 import numpy as np
 
-from .common import add_sweep_args, emit, parse_shards, save_json
+from .common import (
+    add_sweep_args,
+    emit,
+    enable_compile_cache,
+    parse_shards,
+    save_json,
+)
 
 N_WORKERS = 4
 MAX_BATCH = 32
@@ -210,6 +216,7 @@ def main(argv=None):
     ap.add_argument("--workload", default="udp")
     add_sweep_args(ap)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     run(
         n_packets=args.n_packets,
         n_seeds=args.n_seeds,

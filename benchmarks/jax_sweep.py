@@ -31,9 +31,9 @@ through the AOT lower/compile path: every row reports ``compile_s``
 
 CLI / ``run()`` knobs: ``--lanes-scale`` multiplies the seed axis
 (sweep scale grows linearly in lanes with no new compiles);
-``--shards`` partitions the lane axis across local devices via the
-``repro.compat`` ``shard_map`` shims (``auto`` = every local device,
-forced-host CPU devices included).
+``--shards`` partitions the lane axis across local devices via
+``jax.shard_map`` over ``repro.compat.lane_mesh`` (``auto`` = every
+local device, forced-host CPU devices included).
 
 Skips with a named notice (not a crash) on hosts without jax.
 
@@ -46,7 +46,13 @@ import argparse
 
 import numpy as np
 
-from .common import add_sweep_args, emit, parse_shards, save_json
+from .common import (
+    add_sweep_args,
+    emit,
+    enable_compile_cache,
+    parse_shards,
+    save_json,
+)
 
 N_WORKERS = 4
 MAX_BATCH = 64
@@ -94,6 +100,75 @@ SACK_LINK_PPS = 0.85
 #: corec's extra reordering costs <= ~3% FCT p99 vs per-flow-pinned
 #: scaleout even under impairment
 IMPAIRMENT_P99_BAND = 1.03
+#: TCP flow layout of both TCP grids: ``tcp_pkts`` packets split over
+#: ``TCP_FLOWS`` flows that start ``TCP_FLOW_GAP`` apart
+TCP_FLOWS = 2
+TCP_FLOW_GAP = 37.0
+
+
+def _knobs(lane_arrays: dict, params) -> dict:
+    return {k: v for k, v in lane_arrays.items() if k in params._fields}
+
+
+def tcp_flows(tcp_pkts: int = 256):
+    """(per-flow packet counts, per-flow start times) of the TCP grids."""
+    pkts = np.full(TCP_FLOWS, max(8, tcp_pkts // TCP_FLOWS), dtype=np.int32)
+    return pkts, np.arange(TCP_FLOWS, dtype=np.float32) * TCP_FLOW_GAP
+
+
+def forwarder_request(
+    n_seeds: int = N_SEEDS,
+    n_packets: int = 2000,
+    workload: str = "udp",
+    shards: int | str = 1,
+):
+    """The main forwarder grid as one fused ``SweepRequest`` over every
+    jax policy, and the ``(config, seed)`` point of each lane."""
+    from repro.core import SweepRequest
+    from repro.core.jaxplane import LaneParams, TrafficParams, lane_grid
+    from repro.core.policy import jax_policies
+
+    lane_arrays, points = lane_grid(AXES, np.arange(n_seeds))
+    seeds = lane_arrays.pop("__seeds__")
+    req = SweepRequest(
+        scenario="forwarder",
+        policies=jax_policies(),
+        seeds=seeds,
+        arrival=ARRIVALS[workload],
+        lane_params=_knobs(lane_arrays, LaneParams),
+        traffic_params=_knobs(lane_arrays, TrafficParams),
+        n_packets=n_packets,
+        n_workers=N_WORKERS,
+        max_batch=MAX_BATCH,
+        shards=shards,
+    )
+    return req, points
+
+
+def tcp_request(n_seeds: int = N_SEEDS, tcp_pkts: int = 256, shards: int | str = 1):
+    """The main TCP grid as one fused ``SweepRequest`` over every jax
+    policy, and the ``(config, seed)`` point of each lane."""
+    from repro.core import SweepRequest
+    from repro.core.jaxplane import LaneParams, lane_grid
+    from repro.core.policy import jax_policies
+    from repro.core.tcpjax import TcpParams
+
+    lane_arrays, points = lane_grid(TCP_AXES, np.arange(n_seeds))
+    seeds = lane_arrays.pop("__seeds__")
+    flow_pkts, flow_start = tcp_flows(tcp_pkts)
+    req = SweepRequest(
+        scenario="tcp",
+        policies=jax_policies(),
+        seeds=seeds,
+        lane_params=_knobs(lane_arrays, LaneParams),
+        tcp_params=_knobs(lane_arrays, TcpParams),
+        n_packets=flow_pkts,
+        t_start=flow_start,
+        n_workers=N_WORKERS,
+        max_batch=MAX_BATCH,
+        shards=shards,
+    )
+    return req, points
 
 
 def run(
@@ -112,35 +187,17 @@ def run(
         return {"skipped": notice}
 
     from repro.core import SweepRequest, run_sweep
-    from repro.core.jaxplane import LaneParams, TrafficParams, lane_grid
-    from repro.core.policy import jax_policies
+    from repro.core.jaxplane import LaneParams, lane_grid
     from repro.core.tcpjax import TcpParams
 
     n_seeds = max(1, round(n_seeds * lanes_scale))
-    pols = jax_policies()
-    lanes_arrays, points = lane_grid(AXES, np.arange(n_seeds))
-    seeds = lanes_arrays.pop("__seeds__")
-    lanes = seeds.shape[0]
+    request, points = forwarder_request(n_seeds, n_packets, workload, shards)
+    pols = list(request.policies)
+    lanes = len(request.seeds)
     n_cfg = lanes // n_seeds
-    lane_kw = {k: v for k, v in lanes_arrays.items() if k in LaneParams._fields}
-    traffic_kw = {k: v for k, v in lanes_arrays.items() if k in TrafficParams._fields}
 
     timings: dict = {}
-    sweep = run_sweep(
-        SweepRequest(
-            scenario="forwarder",
-            policies=pols,
-            seeds=seeds,
-            arrival=ARRIVALS[workload],
-            lane_params=lane_kw,
-            traffic_params=traffic_kw,
-            n_packets=n_packets,
-            n_workers=N_WORKERS,
-            max_batch=MAX_BATCH,
-            shards=shards,
-        ),
-        timings=timings,
-    )
+    sweep = run_sweep(request, timings=timings)
     results = [sweep[p] for p in pols]
     lanes_total = lanes * len(pols)
     compile_s, run_s = timings["compile_s"], timings["run_s"]
@@ -215,31 +272,13 @@ def run(
             )
 
     # ---- closed-loop TCP lanes: FCT percentiles at sweep scale --------
-    tcp_arrays, tcp_points = lane_grid(TCP_AXES, np.arange(n_seeds))
-    tcp_seeds = tcp_arrays.pop("__seeds__")
-    t_lanes = tcp_seeds.shape[0]
+    tcp_req, tcp_points = tcp_request(n_seeds, tcp_pkts, shards)
+    t_lanes = len(tcp_req.seeds)
     t_ncfg = t_lanes // n_seeds
-    tcp_lane_kw = {k: v for k, v in tcp_arrays.items() if k in LaneParams._fields}
-    tcp_tcp_kw = {k: v for k, v in tcp_arrays.items() if k in TcpParams._fields}
-    n_flows = 2
-    flow_pkts = np.full(n_flows, max(8, tcp_pkts // n_flows), dtype=np.int32)
-    flow_start = np.arange(n_flows, dtype=np.float32) * 37.0
+    n_flows = TCP_FLOWS
+    flow_pkts, flow_start = tcp_flows(tcp_pkts)
     tcp_timings: dict = {}
-    tcp_sweep = run_sweep(
-        SweepRequest(
-            scenario="tcp",
-            policies=pols,
-            seeds=tcp_seeds,
-            lane_params=tcp_lane_kw,
-            tcp_params=tcp_tcp_kw,
-            n_packets=flow_pkts,
-            t_start=flow_start,
-            n_workers=N_WORKERS,
-            max_batch=MAX_BATCH,
-            shards=shards,
-        ),
-        timings=tcp_timings,
-    )
+    tcp_sweep = run_sweep(tcp_req, timings=tcp_timings)
     tcp_results = [tcp_sweep[p] for p in pols]
     t_total = t_lanes * len(pols)
     t_compile, t_run = tcp_timings["compile_s"], tcp_timings["run_s"]
@@ -318,8 +357,8 @@ def run(
     sk_seeds = sk_arrays.pop("__seeds__")
     s_lanes = sk_seeds.shape[0]
     s_ncfg = s_lanes // n_seeds
-    sk_lane_kw = {k: v for k, v in sk_arrays.items() if k in LaneParams._fields}
-    sk_tcp_kw = {k: v for k, v in sk_arrays.items() if k in TcpParams._fields}
+    sk_lane_kw = _knobs(sk_arrays, LaneParams)
+    sk_tcp_kw = _knobs(sk_arrays, TcpParams)
     sk_tcp_kw["sack"] = True
     sk_tcp_kw["link_pps"] = SACK_LINK_PPS
     # deterministic drop-once control rides the loss_rate == 0 configs
@@ -442,6 +481,7 @@ def main(argv=None):
     ap.add_argument("--tcp-pkts", type=int, default=256)
     add_sweep_args(ap)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     shards = parse_shards(args.shards)
     run(
         n_packets=args.n_packets,

@@ -46,7 +46,13 @@ import argparse
 
 import numpy as np
 
-from .common import add_sweep_args, emit, parse_shards, save_json
+from .common import (
+    add_sweep_args,
+    emit,
+    enable_compile_cache,
+    parse_shards,
+    save_json,
+)
 
 N_WORKERS = 4
 MAX_BATCH = 16
@@ -220,6 +226,7 @@ def main(argv=None):
     ap.add_argument("--n-seeds", type=int, default=N_SEEDS)
     add_sweep_args(ap)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     run(
         capacity=args.capacity,
         n_seeds=args.n_seeds,

@@ -19,6 +19,8 @@ import importlib
 import sys
 import traceback
 
+from .common import enable_compile_cache, use_quick_results_dir
+
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser(description=__doc__)
@@ -29,11 +31,10 @@ def main(argv=None) -> None:
     )
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     if args.quick:
         # Shrunk-size runs must never overwrite the tracked full-run
         # artifacts under benchmarks/results/.
-        from .common import use_quick_results_dir
-
         use_quick_results_dir()
 
     # (module name, full kwargs, quick kwargs or None to skip in --quick).

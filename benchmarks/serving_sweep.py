@@ -42,7 +42,13 @@ import argparse
 
 import numpy as np
 
-from .common import add_sweep_args, emit, parse_shards, save_json
+from .common import (
+    add_sweep_args,
+    emit,
+    enable_compile_cache,
+    parse_shards,
+    save_json,
+)
 
 N_WORKERS = 4
 MAX_BATCH = 32
@@ -60,6 +66,43 @@ N_SEEDS = 42
 CAPACITY = 1000  # users (sessions) generated per lane
 
 
+def serving_request(
+    n_seeds: int = N_SEEDS,
+    capacity: int = CAPACITY,
+    arrival: str = "diurnal",
+    session_alpha: float = 1.8,
+    shards: int | str = 1,
+):
+    """The serving grid as one fused ``SweepRequest`` over every jax
+    policy, and the ``(config, seed)`` point of each lane."""
+    from repro.core import SweepRequest
+    from repro.core.jaxplane import ServingParams, TrafficParams, lane_grid
+    from repro.core.policy import jax_policies
+
+    lane_arrays, points = lane_grid(AXES, np.arange(n_seeds))
+    seeds = lane_arrays.pop("__seeds__")
+    traffic_kw = {k: v for k, v in lane_arrays.items() if k in TrafficParams._fields}
+    traffic_kw["session_alpha"] = session_alpha
+    serving_kw = {k: v for k, v in lane_arrays.items() if k in ServingParams._fields}
+    serving_kw["base_workers"] = BASE_WORKERS
+    req = SweepRequest(
+        scenario="serving",
+        policies=jax_policies(),
+        seeds=seeds,
+        arrival=arrival,
+        traffic_params=traffic_kw,
+        serving_params=serving_kw,
+        # the grid is the single source of truth for the knobs here;
+        # registry presets are for bare run_sweep(scenario="serving")
+        use_policy_serving_defaults=False,
+        n_packets=capacity,
+        n_workers=N_WORKERS,
+        max_batch=MAX_BATCH,
+        shards=shards,
+    )
+    return req, points
+
+
 def run(
     capacity: int = CAPACITY,
     n_seeds: int = N_SEEDS,
@@ -75,40 +118,18 @@ def run(
         emit("serving_sweep/SKIPPED", 0.0, notice)
         return {"skipped": notice}
 
-    from repro.core import SweepRequest, run_sweep
-    from repro.core.jaxplane import ServingParams, TrafficParams, lane_grid
-    from repro.core.policy import jax_policies
+    from repro.core import run_sweep
 
     n_seeds = max(1, round(n_seeds * lanes_scale))
-    pols = jax_policies()
-    lanes_arrays, points = lane_grid(AXES, np.arange(n_seeds))
-    seeds = lanes_arrays.pop("__seeds__")
-    lanes = seeds.shape[0]
+    request, points = serving_request(
+        n_seeds, capacity, arrival, session_alpha, shards
+    )
+    pols = list(request.policies)
+    lanes = len(request.seeds)
     n_cfg = lanes // n_seeds
-    traffic_kw = {k: v for k, v in lanes_arrays.items() if k in TrafficParams._fields}
-    traffic_kw["session_alpha"] = session_alpha
-    serving_kw = {k: v for k, v in lanes_arrays.items() if k in ServingParams._fields}
-    serving_kw["base_workers"] = BASE_WORKERS
 
     timings: dict = {}
-    sweep = run_sweep(
-        SweepRequest(
-            scenario="serving",
-            policies=pols,
-            seeds=seeds,
-            arrival=arrival,
-            traffic_params=traffic_kw,
-            serving_params=serving_kw,
-            # the grid is the single source of truth for the knobs here;
-            # registry presets are for bare run_sweep(scenario="serving")
-            use_policy_serving_defaults=False,
-            n_packets=capacity,
-            n_workers=N_WORKERS,
-            max_batch=MAX_BATCH,
-            shards=shards,
-        ),
-        timings=timings,
-    )
+    sweep = run_sweep(request, timings=timings)
     lanes_total = lanes * len(pols)
     compile_s, run_s = timings["compile_s"], timings["run_s"]
     lane_points = lanes_total / run_s
@@ -205,6 +226,7 @@ def main(argv=None):
     ap.add_argument("--arrival", default="diurnal")
     add_sweep_args(ap)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     run(
         capacity=args.capacity,
         n_seeds=args.n_seeds,

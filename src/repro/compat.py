@@ -1,103 +1,32 @@
-"""JAX version-compat shims.
+"""Mesh construction for the sharded paths.
 
-The repo targets "current jax" across a drift window where several
-sharding entry points moved:
-
-* ``shard_map``: ``jax.experimental.shard_map.shard_map(check_rep=...)``
-  (<= 0.4.x) became ``jax.shard_map(check_vma=...)`` (the experimental
-  module is deprecated and later removed).
-* ``make_mesh``: ``jax.make_mesh`` appeared in 0.4.35; older versions
-  only have ``jax.sharding.Mesh`` over ``mesh_utils`` devices.
-* ``tpu_compiler_params``: Pallas renamed
-  ``pltpu.TPUCompilerParams`` (<= 0.4.x / 0.5.x) to
-  ``pltpu.CompilerParams`` (0.6+); the kernels under
-  ``repro/kernels/`` build theirs through here.
-
-All call sites (``optim/compress.py`` users, ``launch/mesh.py``,
-``train/trainer.py``, tests) route through here so a jax upgrade is a
-one-file fix.
+Since jax 0.9, ``jax.make_mesh`` without ``axis_types`` builds
+``Explicit`` axes, under which ``with_sharding_constraint`` in the
+trainer and the lane-sharded ``jax.shard_map`` of the sweep engines
+raise ``ShardingTypeError``.  Every mesh in the repo is built here with
+``Auto`` axes, so the compiler keeps propagating shardings the way
+those call sites expect (``launch/mesh.py``, ``train/trainer.py``,
+``core/jaxplane.py`` / ``core/tcpjax.py``, tests).
 """
 
 from __future__ import annotations
 
-from typing import Any, Sequence
+from typing import Sequence
 
 import jax
+from jax.sharding import AxisType, Mesh
 
-__all__ = [
-    "shard_map",
-    "make_mesh",
-    "lane_mesh",
-    "device_count",
-    "tpu_compiler_params",
-]
+__all__ = ["make_mesh", "lane_mesh"]
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = False):
-    """Version-portable ``shard_map``.
-
-    ``check_vma`` follows the new-API name; on old jax it is forwarded
-    as ``check_rep`` (same meaning: verify per-axis replication/varying
-    annotations, off by default here because the collectives in
-    ``optim/compress.py`` mix gathered and reduced outputs).
-    """
-    if hasattr(jax, "shard_map"):  # jax >= 0.6-ish: top-level API
-        return jax.shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        check_rep=check_vma,
+def make_mesh(shape: Sequence[int], axis_names: Sequence[str]) -> Mesh:
+    """``jax.make_mesh`` over the local devices, every axis ``Auto``."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axis_names), axis_types=(AxisType.Auto,) * len(shape)
     )
 
 
-def make_mesh(shape: Sequence[int], axis_names: Sequence[str]) -> Any:
-    """Version-portable ``jax.make_mesh``."""
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(tuple(shape), tuple(axis_names))
-    from jax.experimental import mesh_utils
-
-    devices = mesh_utils.create_device_mesh(tuple(shape))
-    return jax.sharding.Mesh(devices, tuple(axis_names))
-
-
-def device_count() -> int:
-    """Local devices visible to this process (forced-host CPUs included).
-
-    CI exercises multi-device code paths on CPU by exporting
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` before jax
-    initializes; this is the portable count those paths size against.
-    """
-    return jax.local_device_count()
-
-
-def lane_mesh(n_shards: int) -> Any:
-    """A 1-D ``('lanes',)`` mesh over ``n_shards`` devices.
-
-    The lane-axis sharding entry the vectorized sweep engines
-    (``core/jaxplane.py`` / ``core/tcpjax.py``) partition over; built
-    through :func:`make_mesh` so the jax API drift stays shimmed here.
-    """
+def lane_mesh(n_shards: int) -> Mesh:
+    """A 1-D ``('lanes',)`` mesh over ``n_shards`` devices: the lane axis
+    the vectorized sweep engines partition over."""
     return make_mesh((n_shards,), ("lanes",))
-
-
-def tpu_compiler_params(**kwargs: Any) -> Any:
-    """Version-portable ``pltpu.CompilerParams`` constructor.
-
-    Accepts the class's keyword arguments (``dimension_semantics``,
-    ...) and builds whichever of ``CompilerParams`` (jax >= 0.6) /
-    ``TPUCompilerParams`` (0.4.x-0.5.x) this jax provides.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or getattr(pltpu, "TPUCompilerParams")
-    return cls(**kwargs)

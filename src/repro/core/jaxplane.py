@@ -32,7 +32,7 @@ as a pure JAX program built around a **claim-compacted scan engine**:
   every lane), so a registry-wide sweep compiles and dispatches once
   instead of once per policy.
 * **Sharding**: ``shards > 1`` partitions the lane axis across devices
-  through the :mod:`repro.compat` ``shard_map``/``make_mesh`` shims
+  through ``jax.shard_map`` over :func:`repro.compat.lane_mesh`
   (each segment is padded to a multiple of the device count; CI
   exercises the path on CPU via ``--xla_force_host_platform_device_
   count``).  Lane-axis inputs are donated to the jit on backends that
@@ -365,6 +365,7 @@ class LaneResult(NamedTuple):
     deschedules: jnp.ndarray
     claimed_popcount: jnp.ndarray  # set bits in the packed claim bitmap
     claimed_prefix: jnp.ndarray  # contiguous done prefix of that bitmap
+    claimed_words: jnp.ndarray  # [lanes, n_words] that bitmap, uint32
     sojourn: jnp.ndarray  # [lanes, n] per-packet latency, or [lanes, 0]
     # -- degraded-mode outputs (all zero / -inf-free on fault-free lanes)
     reclaimed: jnp.ndarray  # items re-opened to live workers by a lease
@@ -1465,6 +1466,26 @@ def _sweep_core(
     return tuple(outs)
 
 
+def _attach_prefix(outs, n_bits: int, limit, *, impl: str, interpret: bool):
+    """Add each segment's ``"prefix"``: the contiguous done prefix of its
+    packed claim words (``"words"``), from ONE multi-ring kernel launch
+    over every segment.  Runs inside the lane ``shard_map`` when lanes
+    are sharded, since a Mosaic kernel cannot be partitioned by XLA.
+    ``limit`` caps each row (``None`` = ``n_bits``)."""
+    words = jnp.concatenate([o["words"] for o in outs], axis=0)
+    if limit is None:
+        limit = jnp.full((words.shape[0],), n_bits, dtype=jnp.int32)
+    prefix = kernel_ops.done_prefix_packed(
+        words, limit, n_bits=n_bits, impl=impl, interpret=interpret
+    )
+    res, at = [], 0
+    for o in outs:
+        lanes = o["words"].shape[0]
+        res.append(dict(o, prefix=prefix[at : at + lanes]))
+        at += lanes
+    return tuple(res)
+
+
 def _run_fused_impl(
     blocks,
     *,
@@ -1486,74 +1507,73 @@ def _run_fused_impl(
     prefix_interpret: bool,
     return_times: bool,
 ):
-    core = functools.partial(
-        _sweep_core,
-        pols=pols,
-        workload=workload,
-        service=service,
-        n_packets=n_packets,
-        n_workers=n_workers,
-        max_batch=max_batch,
-        n_flows=n_flows,
-        s_pad=s_pad,
-        chunk=chunk,
-        engine=engine,
-        serving=serving,
-        ovs=ovs,
-        max_cpr=max_cpr,
-        return_times=return_times,
-    )
+    def core(blocks):
+        outs = _sweep_core(
+            blocks,
+            pols=pols,
+            workload=workload,
+            service=service,
+            n_packets=n_packets,
+            n_workers=n_workers,
+            max_batch=max_batch,
+            n_flows=n_flows,
+            s_pad=s_pad,
+            chunk=chunk,
+            engine=engine,
+            serving=serving,
+            ovs=ovs,
+            max_cpr=max_cpr,
+            return_times=return_times,
+        )
+        # exactly-once on the packed words (bit width = the attempt-slot
+        # capacity when retry fan-out is armed)
+        return _attach_prefix(
+            outs,
+            n_packets * max_cpr,
+            None,
+            impl=prefix_impl,
+            interpret=prefix_interpret,
+        )
+
     if n_shards > 1:
         spec = jax.sharding.PartitionSpec("lanes")
-        core = compat.shard_map(
-            core, compat.lane_mesh(n_shards), in_specs=(spec,), out_specs=spec
+        core = jax.shard_map(
+            core,
+            mesh=compat.lane_mesh(n_shards),
+            in_specs=(spec,),
+            out_specs=spec,
+            check_vma=False,
         )
-    outs = core(blocks)
-    # exactly-once on the packed words, one multi-ring prefix launch for
-    # every segment of the fused call (bit width = the attempt-slot
-    # capacity when retry fan-out is armed)
-    n_slots = n_packets * max_cpr
-    words = jnp.concatenate([o["words"] for o in outs], axis=0)
-    prefix = kernel_ops.done_prefix_packed(
-        words,
-        jnp.full((words.shape[0],), n_slots, dtype=jnp.int32),
-        n_bits=n_slots,
-        impl=prefix_impl,
-        interpret=prefix_interpret,
+    return tuple(
+        LaneResult(
+            p50=o["p50"],
+            p99=o["p99"],
+            mean=o["mean"],
+            reorder_pct=o["reorder_pct"],
+            max_distance=o["max_distance"],
+            throughput=o["throughput"],
+            batches=o["batches"],
+            items=o["items"],
+            deschedules=o["deschedules"],
+            claimed_popcount=o["claimed_popcount"],
+            claimed_prefix=o["prefix"],
+            claimed_words=o["words"],
+            sojourn=o["sojourn"],
+            reclaimed=o["reclaimed"],
+            duplicates=o["duplicates"],
+            undelivered=o["undelivered"],
+            drain_t=o["drain_t"],
+            offered=o["offered"],
+            shed=o["shed"],
+            slo_attained=o["slo_attained"],
+            attempts=o["attempts"],
+            delivered=o["delivered"],
+            expired=o["expired"],
+            goodput=o["goodput"],
+            dup_served=o["dup_served"],
+        )
+        for o in core(blocks)
     )
-    results, at = [], 0
-    for o in outs:
-        lanes = o["p50"].shape[0]
-        results.append(
-            LaneResult(
-                p50=o["p50"],
-                p99=o["p99"],
-                mean=o["mean"],
-                reorder_pct=o["reorder_pct"],
-                max_distance=o["max_distance"],
-                throughput=o["throughput"],
-                batches=o["batches"],
-                items=o["items"],
-                deschedules=o["deschedules"],
-                claimed_popcount=o["claimed_popcount"],
-                claimed_prefix=prefix[at : at + lanes],
-                sojourn=o["sojourn"],
-                reclaimed=o["reclaimed"],
-                duplicates=o["duplicates"],
-                undelivered=o["undelivered"],
-                drain_t=o["drain_t"],
-                offered=o["offered"],
-                shed=o["shed"],
-                slo_attained=o["slo_attained"],
-                attempts=o["attempts"],
-                delivered=o["delivered"],
-                expired=o["expired"],
-                goodput=o["goodput"],
-                dup_served=o["dup_served"],
-            )
-        )
-        at += lanes
-    return tuple(results)
 
 
 _FUSED_STATICS = (
@@ -1611,9 +1631,31 @@ def _broadcast_lanes(d: dict, fields, lanes: int, dtype=jnp.float32):
     return vals
 
 
+def _call_fused(fn, args, static: dict, timings: dict | None):
+    """Call a fused jit; with a ``timings`` dict, go through the AOT
+    lower/compile path and record ``compile_s``, ``run_s`` (to
+    ``block_until_ready``) and ``mosaic_kernels``, the number of Pallas
+    kernel calls the compiled program holds (0 where the done-prefix
+    took its XLA path)."""
+    if timings is None:
+        return fn(*args, **static)
+    t0 = time.perf_counter()
+    compiled = fn.lower(*args, **static).compile()
+    t1 = time.perf_counter()
+    outs = compiled(*args)
+    jax.block_until_ready(outs)
+    t2 = time.perf_counter()
+    timings["compile_s"] = t1 - t0
+    timings["run_s"] = t2 - t1
+    timings["mosaic_kernels"] = compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"'
+    )
+    return outs
+
+
 def _resolve_shards(shards) -> int:
     if shards in ("auto", None):
-        return compat.device_count()
+        return jax.local_device_count()
     return max(1, int(shards))
 
 
@@ -1734,18 +1776,7 @@ def _fused_lanes(
         prefix_interpret=prefix_interpret,
         return_times=return_times,
     )
-    blocks = tuple(blocks)
-    if timings is None:
-        outs = fn(blocks, **static)
-    else:
-        t0 = time.perf_counter()
-        compiled = fn.lower(blocks, **static).compile()
-        t1 = time.perf_counter()
-        outs = compiled(blocks)
-        jax.block_until_ready(outs)
-        t2 = time.perf_counter()
-        timings["compile_s"] = t1 - t0
-        timings["run_s"] = t2 - t1
+    outs = _call_fused(fn, (tuple(blocks),), static, timings)
     return [
         jax.tree_util.tree_map(lambda a: a[:lanes], res)
         for res, lanes in zip(outs, orig_lanes)

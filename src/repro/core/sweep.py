@@ -118,8 +118,9 @@ class SweepResult:
 
     ``lanes[name]`` is a :class:`~repro.core.jaxplane.LaneResult`
     (or :class:`~repro.core.tcpjax.TcpLaneResult` for the tcp
-    scenario); ``timings`` carries ``compile_s`` / ``run_s`` when the
-    caller asked for them.
+    scenario); ``timings`` carries ``compile_s`` / ``run_s`` and the
+    compiled program's ``mosaic_kernels`` count when the caller asked
+    for them.
     """
 
     request: SweepRequest
@@ -148,7 +149,8 @@ def run_sweep(request: SweepRequest, timings: dict | None = None) -> SweepResult
 
     Imports the jax engines lazily so the module stays importable on
     DES-only hosts; ``timings`` (a dict, filled in place and echoed on
-    the result) reports AOT compile/run seconds.
+    the result) reports AOT compile/run seconds and the compiled
+    program's Pallas kernel count.
     """
     req = request
     names = list(req.policies) if req.policies is not None else jax_policies()

@@ -66,10 +66,10 @@ keep a lane live so the exactly-once counters still settle), so the
 generous event budget stops costing anything once the closed loops
 drain; policies fuse as statically-bounded lane segments sharing one
 compile; ``shards > 1`` partitions the lane axis across devices via
-the :mod:`repro.compat` shims.  ``engine="reference"`` keeps the
-pre-compaction per-lane scan over the full budget —
-``tests/test_compaction.py`` pins the compacted engine bit-identical
-to it.
+``jax.shard_map`` over :func:`repro.compat.lane_mesh`.
+``engine="reference"`` keeps the pre-compaction per-lane scan over the
+full budget — ``tests/test_compaction.py`` pins the compacted engine
+bit-identical to it.
 
 Parity with ``tcp.py`` is distributional (FCT percentiles, not RNG
 draws) — see ``tests/test_tcpjax.py``; ``TcpSimConfig.queue_hints``
@@ -80,7 +80,6 @@ pin flows identically.
 from __future__ import annotations
 
 import functools
-import time
 from typing import NamedTuple
 
 import jax
@@ -92,7 +91,9 @@ from ..kernels import ops as kernel_ops
 from .jaxplane import (
     FaultParams,
     LaneParams,
+    _attach_prefix,
     _broadcast_lanes,
+    _call_fused,
     _chunked_scan,
     _pad_lanes,
     _resolve_policy,
@@ -180,6 +181,7 @@ class TcpLaneResult(NamedTuple):
     deschedules: jnp.ndarray  # [lanes]
     claimed_popcount: jnp.ndarray  # [lanes] set bits in the claim bitmap
     claimed_prefix: jnp.ndarray  # [lanes] done prefix of that bitmap
+    claimed_words: jnp.ndarray  # [lanes, n_words] that bitmap, uint32
 
 
 def _trailing_ones(x: jnp.ndarray) -> jnp.ndarray:
@@ -644,7 +646,9 @@ def _tcp_step(
             jnp.where(adv, ackno, st["high_ack"][fad])
         )
         done_now = adv & (ackno >= neff[fad] - 1)
-        st["done"] = st["done"].at[fad].set(st["done"][fad] | done_now)
+        # a masked OR, not a scatter: on a TPU, scatters into bool
+        # arrays of many lanes lose updates
+        st["done"] = st["done"] | ((frng == fad) & done_now)
         st["t_done"] = st["t_done"].at[fad].set(
             jnp.where(done_now, t_a, st["t_done"][fad])
         )
@@ -718,16 +722,21 @@ def _tcp_step(
         )
         drop_j = cand_j & (ta_j <= tmin_seq[fad_j, sa_c])
         deliv_j = m & ~drop_j
-        # bool staging + pack_bits_u32 gives an idempotent OR-scatter
-        # (bool scatter-max) even with duplicate (flow, seq) pairs
+        # 0/1 staging + pack_bits_u32 gives an idempotent OR-scatter
+        # (scatter-max) even with duplicate (flow, seq) pairs; int32,
+        # since on a TPU scatters into bool arrays lose updates
         stage = (
-            jnp.zeros((f_cnt + 1, mw * 32), bool).at[fad_j, sa_c].max(deliv_j)
+            jnp.zeros((f_cnt + 1, mw * 32), jnp.int32)
+            .at[fad_j, sa_c]
+            .max(deliv_j.astype(jnp.int32))
         )
         old_rw = st["rwords"]
         new_rw = old_rw | kernel_ops.pack_bits_u32(stage)
         st["rwords"] = new_rw
         dstage = (
-            jnp.zeros((f_cnt + 1, mw * 32), bool).at[fad_j, sa_c].max(drop_j)
+            jnp.zeros((f_cnt + 1, mw * 32), jnp.int32)
+            .at[fad_j, sa_c]
+            .max(drop_j.astype(jnp.int32))
         )
         st["dwords"] = st["dwords"] | kernel_ops.pack_bits_u32(dstage)
         st["tack"] = st["tack"].at[:t_budget].set(jnp.where(m, inf, ta_j))
@@ -1030,59 +1039,59 @@ def _run_tcp_fused_impl(
     prefix_impl: str,
     prefix_interpret: bool,
 ):
-    core = functools.partial(
-        _tcp_core,
-        n_pkts=n_pkts,
-        t_start=t_start,
-        pols=pols,
-        n_flows=n_flows,
-        max_pkts=max_pkts,
-        n_workers=n_workers,
-        max_batch=max_batch,
-        tx_budget=tx_budget,
-        s_pad=s_pad,
-        chunk=chunk,
-        engine=engine,
-        sacks=sacks,
-        send_burst=send_burst,
-    )
+    def core(blocks):
+        outs = _tcp_core(
+            blocks,
+            n_pkts=n_pkts,
+            t_start=t_start,
+            pols=pols,
+            n_flows=n_flows,
+            max_pkts=max_pkts,
+            n_workers=n_workers,
+            max_batch=max_batch,
+            tx_budget=tx_budget,
+            s_pad=s_pad,
+            chunk=chunk,
+            engine=engine,
+            sacks=sacks,
+            send_burst=send_burst,
+        )
+        # exactly-once on the claim bitmap: every transmission put on the
+        # link was claimed by exactly one batch (popcount == prefix == sends)
+        return _attach_prefix(
+            outs,
+            tx_budget,
+            jnp.concatenate([o["sends"] for o in outs], axis=0),
+            impl=prefix_impl,
+            interpret=prefix_interpret,
+        )
+
     if n_shards > 1:
         spec = jax.sharding.PartitionSpec("lanes")
-        core = compat.shard_map(
-            core, compat.lane_mesh(n_shards), in_specs=(spec,), out_specs=spec
+        core = jax.shard_map(
+            core,
+            mesh=compat.lane_mesh(n_shards),
+            in_specs=(spec,),
+            out_specs=spec,
+            check_vma=False,
         )
-    outs = core(blocks)
-    # exactly-once on the claim bitmap: every transmission put on the
-    # link was claimed by exactly one batch (popcount == prefix == sends)
-    words = jnp.concatenate([o["words"] for o in outs], axis=0)
-    sends = jnp.concatenate([o["sends"] for o in outs], axis=0)
-    prefix = kernel_ops.done_prefix_packed(
-        words,
-        sends,
-        n_bits=tx_budget,
-        impl=prefix_impl,
-        interpret=prefix_interpret,
+    return tuple(
+        TcpLaneResult(
+            fct=o["fct"],
+            done=o["done"],
+            retransmissions=o["retx"],
+            spurious=o["spur"],
+            delivered=o["delivered"],
+            sends=o["sends"],
+            batches=o["batches"],
+            items=o["items"],
+            deschedules=o["deschs"],
+            claimed_popcount=o["popcount"],
+            claimed_prefix=o["prefix"],
+            claimed_words=o["words"],
+        )
+        for o in core(blocks)
     )
-    results, at = [], 0
-    for o in outs:
-        lanes = o["sends"].shape[0]
-        results.append(
-            TcpLaneResult(
-                fct=o["fct"],
-                done=o["done"],
-                retransmissions=o["retx"],
-                spurious=o["spur"],
-                delivered=o["delivered"],
-                sends=o["sends"],
-                batches=o["batches"],
-                items=o["items"],
-                deschedules=o["deschs"],
-                claimed_popcount=o["popcount"],
-                claimed_prefix=prefix[at : at + lanes],
-            )
-        )
-        at += lanes
-    return tuple(results)
 
 
 _TCP_STATICS = (
@@ -1227,17 +1236,7 @@ def run_tcp_lanes_fused(
     )
     blocks = tuple(blocks)
     args = (blocks, jnp.asarray(n_arr), jnp.asarray(t_start))
-    if timings is None:
-        outs = fn(*args, **static)
-    else:
-        t0 = time.perf_counter()
-        compiled = fn.lower(*args, **static).compile()
-        t1 = time.perf_counter()
-        outs = compiled(*args)
-        jax.block_until_ready(outs)
-        t2 = time.perf_counter()
-        timings["compile_s"] = t1 - t0
-        timings["run_s"] = t2 - t1
+    outs = _call_fused(fn, args, static, timings)
     return [
         jax.tree_util.tree_map(lambda a: a[:lanes], res)
         for res, lanes in zip(outs, orig_lanes)

@@ -350,7 +350,8 @@ def done_prefix_batch(
 
 
 def pack_bits_u32(bits: jax.Array) -> jax.Array:
-    """Pack a trailing bool axis into uint32 words (AtomicBitmap layout).
+    """Pack a trailing bool (or 0/1 integer) axis into uint32 words
+    (AtomicBitmap layout).
 
     ``bits[..., 32*j + b]`` becomes bit ``b`` of ``words[..., j]`` —
     the exact layout :func:`done_prefix_packed` consumes and

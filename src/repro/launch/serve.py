@@ -38,7 +38,7 @@ def main(argv=None):
     res = eng.run(reqs, rate=args.rate)
     ttft = np.array([r.ttft for r in res])
     lat = np.array([r.latency for r in res])
-    print(f"[serve] {cfg.name} policy={args.policy}: {len(res)}/{len(reqs)} done")
+    print(f"[serve] {cfg.name} policy={args.policy}: {len(res)} requests done")
     ttft_p99 = np.percentile(ttft, 99) * 1e3
     lat_p99 = np.percentile(lat, 99) * 1e3
     print(f"  ttft   mean={ttft.mean() * 1e3:.1f}ms p99={ttft_p99:.1f}ms")

@@ -6,12 +6,10 @@ per-tensor max-abs scales and *error feedback* (the quantization residual
 is added back into the next step's gradient), which keeps convergence
 unharmed in practice (1-bit Adam / EF-SGD literature).
 
-``compressed_pod_allreduce`` is written for use inside ``shard_map``
-(wrap call sites with the version-portable ``repro.compat.shard_map`` —
-the raw jax entry point moved across releases) over the 'pod' axis: it
-all-gathers int8 payloads (1 byte/element over DCN instead of 4) and
-reduces locally.  HLO collective bytes drop ~4x on the
-pod axis — visible in the §Roofline collective term (see EXPERIMENTS.md
+``compressed_pod_allreduce`` is written for use inside
+``jax.shard_map`` over the 'pod' axis: it all-gathers int8 payloads
+(1 byte/element over DCN instead of 4) and reduces locally.  HLO
+collective bytes drop ~4x on the pod axis — visible in the §Roofline collective term (see EXPERIMENTS.md
 §Perf hillclimb #3).
 """
 

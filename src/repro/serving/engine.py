@@ -202,7 +202,11 @@ class InferenceEngine:
     # ------------------------------------------------------------------
     def run(self, requests: List[Request], rate: Optional[float] = None,
             timeout: float = 180.0) -> List[RequestResult]:
-        """Open loop: submit at ``rate`` req/s (None = all at once)."""
+        """Open loop: submit at ``rate`` req/s (None = all at once).
+
+        Raises ``TimeoutError`` when ``timeout`` seconds pass before every
+        request has finished; ``self.results`` keeps the finished ones.
+        """
         threads = [
             threading.Thread(target=self._worker_loop, args=(w,), daemon=True)
             for w in range(self.ecfg.n_workers)
@@ -275,4 +279,9 @@ class InferenceEngine:
         self._release()  # hand back the trailing done-prefix (drain)
         for t in threads:
             t.join(timeout=2.0)
+        if len(self.results) < n_total:
+            raise TimeoutError(
+                f"{len(self.results)}/{n_total} requests finished within "
+                f"the {timeout}s deadline"
+            )
         return list(self.results)

@@ -67,7 +67,7 @@ def test_compressed_allreduce_with_error_feedback():
     per step and error feedback keeps the *accumulated* bias near zero."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import make_mesh, shard_map
+    from repro.compat import make_mesh
 
     mesh = make_mesh((1,), ("pod",))
     g = {"w": jnp.asarray(np.random.default_rng(1).normal(size=(128,)))}
@@ -76,7 +76,7 @@ def test_compressed_allreduce_with_error_feedback():
     def f(g, e):
         return compressed_pod_allreduce(g, e, "pod")
 
-    fm = shard_map(
+    fm = jax.shard_map(
         f, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()), check_vma=False
     )
     red, e2 = fm(g, e)

@@ -52,6 +52,18 @@ def test_engine_completes_all_requests(policy):
     assert all(r.t_done >= r.t_first_token >= r.t_arrival for r in res)
 
 
+def test_unfinished_run_raises():
+    """A run cut by its deadline raises instead of returning a partial
+    result list; the finished requests stay on the engine."""
+    eng = InferenceEngine(
+        TINY,
+        EngineConfig(n_slots=2, max_seq=24, n_workers=1, policy="corec", eos_token=-1),
+    )
+    with pytest.raises(TimeoutError, match="/6 requests finished"):
+        eng.run(_requests(6, new_tokens=8), timeout=0.0)
+    assert len(eng.results) < 6
+
+
 def test_greedy_decode_deterministic_across_policies():
     """Same request => identical tokens regardless of ingestion policy
     (the queue discipline must not change model outputs)."""
