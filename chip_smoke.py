@@ -314,7 +314,9 @@ def sharded_phase(n_seeds: int, shards: int) -> dict:
             equal_nan=True,
         )
         for p in one.policies
+        # scan_steps is each shard's own chunk count, not a result
         for f in LaneResult._fields
+        if f != "scan_steps"
     )
     # where the sharded lanes live: rows of every policy's claim words
     # on each device

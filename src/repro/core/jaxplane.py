@@ -90,6 +90,7 @@ import numpy as np
 
 from .. import compat
 from ..kernels import ops as kernel_ops
+from . import record
 
 __all__ = [
     "JaxPolicy",
@@ -352,7 +353,14 @@ def _pop_overload(sp: dict) -> OverloadConfig:
 
 
 class LaneResult(NamedTuple):
-    """Per-lane outputs of :func:`run_lanes` (each field is [lanes])."""
+    """Per-lane outputs of :func:`run_lanes` (each field is [lanes]).
+
+    The last two fields count what the engine ran, not what it
+    simulated: ``active_steps`` the scan steps taken before the lane's
+    done predicate held, ``scan_steps`` the steps its segment's scan
+    ran (chunks whose body executed, times ``chunk``; every step under
+    ``engine="reference"``; the shard's own with ``shards > 1``).
+    """
 
     p50: jnp.ndarray
     p99: jnp.ndarray
@@ -386,6 +394,9 @@ class LaneResult(NamedTuple):
     expired: jnp.ndarray  # served copies past their deadline or lost
     goodput: jnp.ndarray  # unique requests with >= 1 timely response
     dup_served: jnp.ndarray  # timely responses beyond the first per request
+    # -- scan counters (int32; what the engine ran, not what it simulated)
+    active_steps: jnp.ndarray  # steps taken before the lane's done predicate held
+    scan_steps: jnp.ndarray  # steps its segment's scan ran (the shard's, sharded)
 
 
 # ----------------------------------------------------------------------
@@ -1125,7 +1136,14 @@ def _lane_setup(
 
 
 def _reference_lane(
-    pol: JaxPolicy, mb: int, serving: bool, ov: OverloadConfig, params, sparams, su
+    pol: JaxPolicy,
+    mb: int,
+    serving: bool,
+    ov: OverloadConfig,
+    lane_done,
+    params,
+    sparams,
+    su,
 ):
     """The pre-compaction per-claim scan: windows written inside the step.
 
@@ -1135,6 +1153,8 @@ def _reference_lane(
     reconstruction against, bit for bit.  In serving mode a separate
     claimed grid is maintained (shed spans are claimed but never get a
     finite completion, so ``isfinite(done)`` no longer implies claimed).
+    Every step runs; ``lane_done(st, su)`` only counts the steps taken
+    before it held (``active_steps``).
     """
     q_arr, cumsvc = su["q_arr"], su["cumsvc"]
     qid, rank = su["qid"], su["rank"]
@@ -1149,8 +1169,9 @@ def _reference_lane(
     lane_st0 = jax.tree_util.tree_map(lambda x: x[0], _init_state(1, w_count))
 
     def step(carry, xs):
-        st, done_qr, clm_qr = carry
+        st, done_qr, clm_qr, active = carry
         u, stall = xs
+        active = active + (~lane_done(st, su)).astype(jnp.int32)
         st2, rec = _claim_step(
             pol, mb, serving, ov, params, sparams, q_arr, cumsvc, flt, st, u, stall
         )
@@ -1175,28 +1196,37 @@ def _reference_lane(
             clm_qr = jax.lax.dynamic_update_slice(
                 clm_qr, srow[None], (rec.q, ptr_s)
             )
-        return (st2, done_qr, clm_qr), None
+        return (st2, done_qr, clm_qr, active), None
 
-    (st, done_qr, clm_qr), _ = jax.lax.scan(
-        step, (lane_st0, done_qr0, clm_qr0), (su["u"], su["stalls"])
+    carry0 = (lane_st0, done_qr0, clm_qr0, jnp.int32(0))
+    (st, done_qr, clm_qr, active), _ = jax.lax.scan(
+        step, carry0, (su["u"], su["stalls"])
     )
     done = done_qr[qid, rank]
     claimed = clm_qr[qid, rank] if serving else jnp.isfinite(done)
-    return st, done, claimed
+    return st, done, claimed, active
 
 
 # ----------------------------------------------------------------------
 # Chunked scan with a real done short-circuit (scan outside the vmap)
 # ----------------------------------------------------------------------
-def _chunked_scan(body, carry0, xs, done_fn, chunk: int):
+def _chunked_scan(body, carry0, xs, lane_done, chunk: int):
     """``lax.scan`` over chunks of ``chunk`` steps with early exit.
 
     ``body`` advances ALL lanes one step (it is vmapped internally by
-    the caller); ``done_fn(carry) -> bool[]`` is a scalar predicate
-    over the full carry.  Each chunk is guarded by ``lax.cond``: once
-    every lane reports done, remaining chunks skip both the state
-    update and the per-step outputs (zero records — masked downstream).
-    The leading xs axis must be a multiple of ``chunk``.
+    the caller); ``lane_done(carry) -> bool[lanes]`` is each lane's
+    done predicate.  Each chunk is guarded by ``lax.cond``: once every
+    lane reports done, remaining chunks skip both the state update and
+    the per-step outputs (zero records — masked downstream).  The
+    leading xs axis must be a multiple of ``chunk``.
+
+    Returns ``(carry, ys, active_steps, scan_steps)``, the last two
+    int32 per lane: the steps taken before the lane's own predicate
+    held, and the steps the scan ran (chunks whose ``run`` branch
+    executed, times ``chunk``).  The output shapes and the ``run``
+    branch scan the same counting body, so JAX traces the step once.
+    A chunk that runs is one ``while`` op under the name scope
+    ``chunk.<chunk>``.
     """
     s_total = jax.tree_util.tree_leaves(xs)[0].shape[0]
     n_chunks = s_total // chunk
@@ -1204,23 +1234,38 @@ def _chunked_scan(body, carry0, xs, done_fn, chunk: int):
         lambda x: x.reshape((n_chunks, chunk) + x.shape[1:]), xs
     )
     x0 = jax.tree_util.tree_map(lambda x: x[0], xs_c)
-    ys_aval = jax.eval_shape(lambda c, x: jax.lax.scan(body, c, x)[1], carry0, x0)
+
+    def counted(carry, x):
+        c, active = carry
+        active = active + (~lane_done(c)).astype(jnp.int32)
+        c, y = body(c, x)
+        return (c, active), y
+
+    active0 = jnp.zeros(jax.eval_shape(lane_done, carry0).shape, jnp.int32)
+    ys_aval = jax.eval_shape(
+        lambda c, x: jax.lax.scan(counted, c, x)[1], (carry0, active0), x0
+    )
 
     def chunk_body(carry, xc):
-        def run(c):
-            return jax.lax.scan(body, c, xc)
+        def run(carry):
+            c, active, chunks = carry
+            with jax.named_scope(f"chunk.{chunk}"):
+                (c, active), ys = jax.lax.scan(counted, (c, active), xc)
+            return (c, active, chunks + 1), ys
 
-        def skip(c):
+        def skip(carry):
             zeros = jax.tree_util.tree_map(
                 lambda a: jnp.zeros(a.shape, a.dtype), ys_aval
             )
-            return c, zeros
+            return carry, zeros
 
-        return jax.lax.cond(done_fn(carry), skip, run, carry)
+        return jax.lax.cond(jnp.all(lane_done(carry[0])), skip, run, carry)
 
-    carry, ys = jax.lax.scan(chunk_body, carry0, xs_c)
+    (carry, active, chunks), ys = jax.lax.scan(
+        chunk_body, (carry0, active0, jnp.int32(0)), xs_c
+    )
     ys = jax.tree_util.tree_map(lambda y: y.reshape((s_total,) + y.shape[2:]), ys)
-    return carry, ys
+    return carry, ys, active, jnp.full(active.shape, chunks * chunk, jnp.int32)
 
 
 # ----------------------------------------------------------------------
@@ -1273,33 +1318,44 @@ def _sweep_core(
     for pol, ov, (params, traffic, fparams, sparams, seeds) in zip(
         pols, ovs, blocks
     ):
-        setup = jax.vmap(
-            functools.partial(
-                _lane_setup,
-                pol,
-                workload,
-                service,
-                n,
-                n_slots,
-                n_flows,
-                n_workers,
-                s_pad,
-                serving,
-                ov,
-            )
-        )(params, traffic, fparams, sparams, seeds)
+        with jax.named_scope(f"seg.{pol.name}"):
+            setup = jax.vmap(
+                functools.partial(
+                    _lane_setup,
+                    pol,
+                    workload,
+                    service,
+                    n,
+                    n_slots,
+                    n_flows,
+                    n_workers,
+                    s_pad,
+                    serving,
+                    ov,
+                )
+            )(params, traffic, fparams, sparams, seeds)
         setups.append(setup)
         states.append(_init_state(seeds.shape[0], n_workers))
+
+    def lane_done(st, su):
+        # a lane is finished when it drained OR wedged (no claimable
+        # work remains: dead lock holder, unleased stranded span) —
+        # wedged lanes must not burn the budget.  Serving lanes drain
+        # at their own offered load (shed requests count: they
+        # consumed a claim slot).
+        if serving:
+            return st.halted | (st.items + st.shed >= su["offered"])
+        return st.halted | (st.items >= n)
 
     if engine == "reference":
         finals = []
         for pol, ov, (params, _, _, sparams, _), su in zip(
             pols, ovs, blocks, setups
         ):
-            ref = jax.vmap(functools.partial(_reference_lane, pol, mb, serving, ov))(
-                params, sparams, su
-            )
-            finals.append(ref)
+            lane = functools.partial(_reference_lane, pol, mb, serving, ov, lane_done)
+            with jax.named_scope(f"seg.{pol.name}"), jax.named_scope("scan"):
+                st, done, claimed, active = jax.vmap(lane)(params, sparams, su)
+            finals.append((st, done, claimed, active, jnp.full_like(active, s_pad)))
     elif engine == "compacted":
         # one specialized chunked scan PER policy segment, all inside
         # the one jitted call: each policy's lanes stop paying for the
@@ -1321,149 +1377,152 @@ def _sweep_core(
                     params, sparams, su["q_arr"], su["cumsvc"], flt, carry, u, stall
                 )
 
-            def done_fn(st, su=su):
-                # a lane is finished when it drained OR wedged (no
-                # claimable work remains: dead lock holder, unleased
-                # stranded span) — wedged lanes must not burn the budget.
-                # Serving lanes drain at their own offered load (shed
-                # requests count: they consumed a claim slot).
-                if serving:
-                    return jnp.all(st.halted | (st.items + st.shed >= su["offered"]))
-                return jnp.all(st.halted | (st.items >= n))
-
-            st, rec = _chunked_scan(
-                body, st0, (su["u"].T, su["stalls"].T), done_fn, chunk
-            )
-            rec_l = ClaimRecord(*(x.T for x in rec))  # [S, Lp] -> [Lp, S]
-            done, claimed = jax.vmap(_scatter_claims)(
-                rec_l, su["qid"], su["rank"], su["cumsvc"]
-            )
-            finals.append((st, done, claimed))
+            with jax.named_scope(f"seg.{pol.name}"):
+                xs = (su["u"].T, su["stalls"].T)
+                with jax.named_scope("scan"):
+                    st, rec, active, scanned = _chunked_scan(
+                        body, st0, xs, functools.partial(lane_done, su=su), chunk
+                    )
+                with jax.named_scope("claims"):
+                    rec_l = ClaimRecord(*(x.T for x in rec))  # [S, Lp] -> [Lp, S]
+                    done, claimed = jax.vmap(_scatter_claims)(
+                        rec_l, su["qid"], su["rank"], su["cumsvc"]
+                    )
+            finals.append((st, done, claimed, active, scanned))
     else:
         raise ValueError(f"unknown engine {engine!r}")
 
     outs = []
-    for ov, (_, _, _, sparams, _), su, (st, done, claimed) in zip(
-        ovs, blocks, setups, finals
+    for pol, ov, (_, _, _, sparams, _), su, fin in zip(
+        pols, ovs, blocks, setups, finals
     ):
-        words = kernel_ops.pack_bits_u32(claimed)
-        ratio, max_dist = jax.vmap(reorder_metrics)(done)
-        if serving:
-            # Open-loop metrics: only delivered requests have latencies
-            # (shed and stranded carry done=+inf, horizon-masked slots
-            # carry arr=done=+inf), so every aggregate masks on
-            # delivery and percentiles interpolate over the delivered
-            # prefix of the sorted row — matching np.percentile on the
-            # delivered subset exactly (pinned by tests).  A served
-            # attempt only counts delivered when its response survives
-            # drop_rate (counter-hash on request + attempt; all-false
-            # at the 0.0 identity) AND, with a timeout armed, returns
-            # within timeout of ITS OWN submission.
-            served = jnp.isfinite(done)
-            lost = (
-                hash_u01(
-                    su["lseed"][:, None] ^ jnp.uint32(_DROP_SALT),
-                    su["parent"],
-                    su["att"],
-                )
-                < sparams.drop_rate[:, None]
+        st, done, claimed, active, scanned = fin
+        with jax.named_scope(f"seg.{pol.name}"), jax.named_scope("post_scan"):
+            out = _segment_outputs(
+                n, serving, ov, sparams, su, st, done, claimed, return_times
             )
-            delivered = served & ~lost
-            attempts = su["offered"].astype(jnp.int32)
-            if ov.extended:
-                # request-level accounting: a request is good when ANY
-                # of its attempt copies answers within its deadline;
-                # later timely copies are duplicate work (dup_served)
-                delivered = delivered & (done <= su["arr"] + jnp.float32(ov.timeout))
-                lanes_i = jnp.arange(done.shape[0])[:, None]
-                first_ok = (
-                    jnp.full((done.shape[0], n), jnp.inf)
-                    .at[lanes_i, su["parent"]]
-                    .min(jnp.where(delivered, done, jnp.inf))
-                )
-                deliv_req = jnp.isfinite(first_ok)
-                sojourn = jnp.where(deliv_req, first_ok - su["arr0"], jnp.inf)
-                arr_lat = su["arr0"]
-                offered = su["offered_req"].astype(jnp.int32)
-            else:
-                sojourn = jnp.where(delivered, done - su["arr"], jnp.inf)
-                deliv_req = delivered
-                arr_lat = su["arr"]
-                offered = su["offered"].astype(jnp.int32)
-            n_del = jnp.sum(deliv_req, axis=-1).astype(jnp.int32)
-            svals = jnp.sort(sojourn, axis=-1)
-            p50 = _masked_percentile(svals, n_del, 50.0)
-            p99 = _masked_percentile(svals, n_del, 99.0)
-            mean = jnp.sum(
-                jnp.where(deliv_req, sojourn, 0.0), axis=-1
-            ) / jnp.maximum(n_del, 1)
-            ok = deliv_req & (sojourn <= sparams.slo_target[:, None])
-            slo_att = jnp.sum(ok, axis=-1) / jnp.maximum(offered, 1)
-            drain_t = jnp.max(
-                jnp.where(jnp.isfinite(done), done, -jnp.inf), axis=-1
-            )
-            t_first = jnp.min(arr_lat, axis=-1)
-            span = jnp.maximum(drain_t - t_first, 1e-9)
-            throughput = st.items / span
-            undelivered = (attempts - st.items - st.shed).astype(jnp.int32)
-            n_deliv_cp = jnp.sum(delivered, axis=-1).astype(jnp.int32)
-            expired = st.items - n_deliv_cp
-            goodput = n_del
-            dup_served = n_deliv_cp - goodput
-        else:
-            sojourn = done - su["arr"]
-            pct = jnp.percentile(sojourn, jnp.asarray([50.0, 99.0]), axis=-1)
-            p50, p99 = pct[0], pct[1]
-            mean = jnp.mean(sojourn, axis=-1)
-            offered = jnp.full(st.items.shape, n, dtype=jnp.int32)
-            # closed loop: every request is offered and none shed, so
-            # attainment degenerates to the delivered fraction
-            slo_att = st.items.astype(jnp.float32) / n
-            # Undelivered items (wedged lanes) carry done=+inf; the
-            # recovery edge is the last *finite* completion, and the
-            # busy span uses it so faulted lanes still report a finite
-            # throughput denominator.
-            drain_t = jnp.max(jnp.where(jnp.isfinite(done), done, -jnp.inf), axis=-1)
-            span = drain_t - jnp.min(su["arr"], axis=-1)
-            throughput = n / span
-            undelivered = (n - st.items).astype(jnp.int32)
-            # no client plane off serving mode: every claimed item is a
-            # delivered original
-            attempts = offered
-            expired = jnp.zeros_like(st.items)
-            goodput = st.items
-            dup_served = jnp.zeros_like(st.items)
-        outs.append(
-            dict(
-                p50=p50,
-                p99=p99,
-                mean=mean,
-                reorder_pct=100.0 * ratio,
-                max_distance=max_dist,
-                throughput=throughput,
-                batches=st.batches,
-                items=st.items,
-                deschedules=st.deschs,
-                claimed_popcount=jnp.sum(
-                    jax.lax.population_count(words), axis=-1
-                ).astype(jnp.int32),
-                words=words,
-                reclaimed=st.reclaimed,
-                duplicates=st.dups,
-                undelivered=undelivered,
-                drain_t=drain_t,
-                offered=offered,
-                shed=st.shed,
-                slo_attained=slo_att.astype(jnp.float32),
-                attempts=attempts,
-                delivered=goodput + dup_served,
-                expired=expired,
-                goodput=goodput,
-                dup_served=dup_served,
-                sojourn=sojourn if return_times else sojourn[:, :0],
-            )
-        )
+        outs.append(dict(out, active_steps=active, scan_steps=scanned))
     return tuple(outs)
+
+
+def _segment_outputs(n, serving, ov, sparams, su, st, done, claimed, return_times):
+    """One segment's per-lane outputs from its final lane state and the
+    reconstructed completions: latency, reorder and accounting."""
+    words = kernel_ops.pack_bits_u32(claimed)
+    ratio, max_dist = jax.vmap(reorder_metrics)(done)
+    if serving:
+        # Open-loop metrics: only delivered requests have latencies
+        # (shed and stranded carry done=+inf, horizon-masked slots
+        # carry arr=done=+inf), so every aggregate masks on
+        # delivery and percentiles interpolate over the delivered
+        # prefix of the sorted row — matching np.percentile on the
+        # delivered subset exactly (pinned by tests).  A served
+        # attempt only counts delivered when its response survives
+        # drop_rate (counter-hash on request + attempt; all-false
+        # at the 0.0 identity) AND, with a timeout armed, returns
+        # within timeout of ITS OWN submission.
+        served = jnp.isfinite(done)
+        lost = (
+            hash_u01(
+                su["lseed"][:, None] ^ jnp.uint32(_DROP_SALT),
+                su["parent"],
+                su["att"],
+            )
+            < sparams.drop_rate[:, None]
+        )
+        delivered = served & ~lost
+        attempts = su["offered"].astype(jnp.int32)
+        if ov.extended:
+            # request-level accounting: a request is good when ANY
+            # of its attempt copies answers within its deadline;
+            # later timely copies are duplicate work (dup_served)
+            delivered = delivered & (done <= su["arr"] + jnp.float32(ov.timeout))
+            lanes_i = jnp.arange(done.shape[0])[:, None]
+            first_ok = (
+                jnp.full((done.shape[0], n), jnp.inf)
+                .at[lanes_i, su["parent"]]
+                .min(jnp.where(delivered, done, jnp.inf))
+            )
+            deliv_req = jnp.isfinite(first_ok)
+            sojourn = jnp.where(deliv_req, first_ok - su["arr0"], jnp.inf)
+            arr_lat = su["arr0"]
+            offered = su["offered_req"].astype(jnp.int32)
+        else:
+            sojourn = jnp.where(delivered, done - su["arr"], jnp.inf)
+            deliv_req = delivered
+            arr_lat = su["arr"]
+            offered = su["offered"].astype(jnp.int32)
+        n_del = jnp.sum(deliv_req, axis=-1).astype(jnp.int32)
+        svals = jnp.sort(sojourn, axis=-1)
+        p50 = _masked_percentile(svals, n_del, 50.0)
+        p99 = _masked_percentile(svals, n_del, 99.0)
+        mean = jnp.sum(
+            jnp.where(deliv_req, sojourn, 0.0), axis=-1
+        ) / jnp.maximum(n_del, 1)
+        ok = deliv_req & (sojourn <= sparams.slo_target[:, None])
+        slo_att = jnp.sum(ok, axis=-1) / jnp.maximum(offered, 1)
+        drain_t = jnp.max(
+            jnp.where(jnp.isfinite(done), done, -jnp.inf), axis=-1
+        )
+        t_first = jnp.min(arr_lat, axis=-1)
+        span = jnp.maximum(drain_t - t_first, 1e-9)
+        throughput = st.items / span
+        undelivered = (attempts - st.items - st.shed).astype(jnp.int32)
+        n_deliv_cp = jnp.sum(delivered, axis=-1).astype(jnp.int32)
+        expired = st.items - n_deliv_cp
+        goodput = n_del
+        dup_served = n_deliv_cp - goodput
+    else:
+        sojourn = done - su["arr"]
+        pct = jnp.percentile(sojourn, jnp.asarray([50.0, 99.0]), axis=-1)
+        p50, p99 = pct[0], pct[1]
+        mean = jnp.mean(sojourn, axis=-1)
+        offered = jnp.full(st.items.shape, n, dtype=jnp.int32)
+        # closed loop: every request is offered and none shed, so
+        # attainment degenerates to the delivered fraction
+        slo_att = st.items.astype(jnp.float32) / n
+        # Undelivered items (wedged lanes) carry done=+inf; the
+        # recovery edge is the last *finite* completion, and the
+        # busy span uses it so faulted lanes still report a finite
+        # throughput denominator.
+        drain_t = jnp.max(jnp.where(jnp.isfinite(done), done, -jnp.inf), axis=-1)
+        span = drain_t - jnp.min(su["arr"], axis=-1)
+        throughput = n / span
+        undelivered = (n - st.items).astype(jnp.int32)
+        # no client plane off serving mode: every claimed item is a
+        # delivered original
+        attempts = offered
+        expired = jnp.zeros_like(st.items)
+        goodput = st.items
+        dup_served = jnp.zeros_like(st.items)
+    return dict(
+        p50=p50,
+        p99=p99,
+        mean=mean,
+        reorder_pct=100.0 * ratio,
+        max_distance=max_dist,
+        throughput=throughput,
+        batches=st.batches,
+        items=st.items,
+        deschedules=st.deschs,
+        claimed_popcount=jnp.sum(
+            jax.lax.population_count(words), axis=-1
+        ).astype(jnp.int32),
+        words=words,
+        reclaimed=st.reclaimed,
+        duplicates=st.dups,
+        undelivered=undelivered,
+        drain_t=drain_t,
+        offered=offered,
+        shed=st.shed,
+        slo_attained=slo_att.astype(jnp.float32),
+        attempts=attempts,
+        delivered=goodput + dup_served,
+        expired=expired,
+        goodput=goodput,
+        dup_served=dup_served,
+        sojourn=sojourn if return_times else sojourn[:, :0],
+    )
 
 
 def _attach_prefix(outs, n_bits: int, limit, *, impl: str, interpret: bool):
@@ -1471,13 +1530,15 @@ def _attach_prefix(outs, n_bits: int, limit, *, impl: str, interpret: bool):
     packed claim words (``"words"``), from ONE multi-ring kernel launch
     over every segment.  Runs inside the lane ``shard_map`` when lanes
     are sharded, since a Mosaic kernel cannot be partitioned by XLA.
-    ``limit`` caps each row (``None`` = ``n_bits``)."""
+    ``limit`` caps each row (``None`` = ``n_bits``).  The launch sits
+    under the name scope ``done_prefix``."""
     words = jnp.concatenate([o["words"] for o in outs], axis=0)
     if limit is None:
         limit = jnp.full((words.shape[0],), n_bits, dtype=jnp.int32)
-    prefix = kernel_ops.done_prefix_packed(
-        words, limit, n_bits=n_bits, impl=impl, interpret=interpret
-    )
+    with jax.named_scope("done_prefix"):
+        prefix = kernel_ops.done_prefix_packed(
+            words, limit, n_bits=n_bits, impl=impl, interpret=interpret
+        )
     res, at = [], 0
     for o in outs:
         lanes = o["words"].shape[0]
@@ -1571,6 +1632,8 @@ def _run_fused_impl(
             expired=o["expired"],
             goodput=o["goodput"],
             dup_served=o["dup_served"],
+            active_steps=o["active_steps"],
+            scan_steps=o["scan_steps"],
         )
         for o in core(blocks)
     )
@@ -1709,10 +1772,43 @@ def _fused_lanes(
     :class:`ServingParams` horizon decides how many of those drawn
     arrivals are offered — and results report ``offered`` / ``shed`` /
     ``slo_attained`` with delivery-masked latency aggregates.
+
+    For a profiler trace, building the lane blocks is marked
+    ``repro.prepare`` and the jitted call ``repro.dispatch``; the
+    program's set-up goes to :mod:`repro.core.record`.
     """
     requests = list(requests)
     if not requests:
         raise ValueError("run_lanes_fused: empty request list")
+    record.install()
+    with jax.profiler.TraceAnnotation("repro.prepare"):
+        args, static, orig_lanes = _fused_args(
+            requests, serving, shards, chunk, n_packets, claim_budget
+        )
+    static.update(
+        workload=workload,
+        service=service,
+        n_packets=n_packets,
+        n_workers=n_workers,
+        max_batch=max_batch,
+        n_flows=n_flows,
+        engine=engine,
+        prefix_impl=prefix_impl,
+        prefix_interpret=prefix_interpret,
+        return_times=return_times,
+    )
+    fn = _fused_jit(jax.default_backend() != "cpu")
+    with jax.profiler.TraceAnnotation("repro.dispatch"):
+        outs = _call_fused(fn, args, static, timings)
+    return [
+        jax.tree_util.tree_map(lambda a: a[:lanes], res)
+        for res, lanes in zip(outs, orig_lanes)
+    ]
+
+
+def _fused_args(requests, serving, shards, chunk, n_packets, claim_budget):
+    """The lane blocks of the fused call, its shape statics and each
+    request's lane count (before padding to the shard count)."""
     serving = serving or any(req.get("serving_params") for req in requests)
     n_shards = _resolve_shards(shards)
     chunk = max(1, int(chunk))
@@ -1754,33 +1850,16 @@ def _fused_lanes(
     budget = n_slots if claim_budget is None else int(claim_budget)
     budget = max(1, min(budget, n_slots))
     s_pad = -(-budget // chunk) * chunk
-
-    donate = jax.default_backend() != "cpu"
-    fn = _fused_jit(donate)
     static = dict(
         pols=tuple(pols),
-        workload=workload,
-        service=service,
-        n_packets=n_packets,
-        n_workers=n_workers,
-        max_batch=max_batch,
-        n_flows=n_flows,
         s_pad=s_pad,
         chunk=chunk,
         n_shards=n_shards,
-        engine=engine,
         serving=serving,
         ovs=tuple(ovs),
         max_cpr=max_cpr,
-        prefix_impl=prefix_impl,
-        prefix_interpret=prefix_interpret,
-        return_times=return_times,
     )
-    outs = _call_fused(fn, (tuple(blocks),), static, timings)
-    return [
-        jax.tree_util.tree_map(lambda a: a[:lanes], res)
-        for res, lanes in zip(outs, orig_lanes)
-    ]
+    return (tuple(blocks),), static, orig_lanes
 
 
 def run_lanes_fused(requests, **kw):

@@ -63,6 +63,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field, replace
 from typing import Any, Mapping, Optional, Sequence, Tuple, Union
 
+from . import record
 from .policy import _fused_requests, get_spec, jax_policies
 from .servingjax import ARRIVAL_WORKLOADS
 
@@ -118,9 +119,13 @@ class SweepResult:
 
     ``lanes[name]`` is a :class:`~repro.core.jaxplane.LaneResult`
     (or :class:`~repro.core.tcpjax.TcpLaneResult` for the tcp
-    scenario); ``timings`` carries ``compile_s`` / ``run_s`` and the
-    compiled program's ``mosaic_kernels`` count when the caller asked
-    for them.
+    scenario), device arrays that may still be computing.  Besides the
+    simulated statistics each lane carries two int32 scan counters:
+    ``active_steps``, the steps taken before the lane was done, and
+    ``scan_steps``, the steps its policy segment's scan ran (chunks
+    that ran times ``chunk``; the shard's own when ``shards > 1``).
+    ``timings`` carries ``compile_s`` / ``run_s`` and the compiled
+    program's ``mosaic_kernels`` count when the caller asked for them.
     """
 
     request: SweepRequest
@@ -151,8 +156,24 @@ def run_sweep(request: SweepRequest, timings: dict | None = None) -> SweepResult
     DES-only hosts; ``timings`` (a dict, filled in place and echoed on
     the result) reports AOT compile/run seconds and the compiled
     program's Pallas kernel count.
+
+    The call is instrumented (see README "Reading a sweep from the
+    inside"): profiler spans ``repro.sweep`` around it, with
+    ``repro.prepare`` and ``repro.dispatch`` inside; name scopes on the
+    device ops; set-up phases of each compiled program and the scan
+    counters of the last call in :mod:`repro.core.record`.
     """
-    req = request
+    from jax import profiler
+
+    with profiler.TraceAnnotation("repro.sweep"):
+        result = _run_sweep(request, timings)
+    record.note_sweep(result.lanes)
+    return result
+
+
+def _run_sweep(req: SweepRequest, timings: dict | None) -> SweepResult:
+    from jax import profiler
+
     names = list(req.policies) if req.policies is not None else jax_policies()
     if req.scenario in ("forwarder", "queueing", "serving"):
         from .jaxplane import _fused_lanes
@@ -163,16 +184,17 @@ def run_sweep(request: SweepRequest, timings: dict | None = None) -> SweepResult
         else:
             workload = ARRIVAL_WORKLOADS[req.arrival]
             service = req.service or ("HT" if serving else "fwd")
-        reqs = _fused_requests(
-            req.seeds,
-            lane_params=dict(req.lane_params),
-            policies=names,
-            traffic_params=dict(req.traffic_params),
-            fault_params=dict(req.fault_params),
-        )
-        if serving:
-            for r in reqs:
-                r["serving_params"] = _serving_knobs(req, r["policy"])
+        with profiler.TraceAnnotation("repro.prepare"):
+            reqs = _fused_requests(
+                req.seeds,
+                lane_params=dict(req.lane_params),
+                policies=names,
+                traffic_params=dict(req.traffic_params),
+                fault_params=dict(req.fault_params),
+            )
+            if serving:
+                for r in reqs:
+                    r["serving_params"] = _serving_knobs(req, r["policy"])
         results = _fused_lanes(
             reqs,
             workload=workload,
@@ -194,13 +216,14 @@ def run_sweep(request: SweepRequest, timings: dict | None = None) -> SweepResult
     elif req.scenario == "tcp":
         from .tcpjax import run_tcp_lanes_fused
 
-        reqs = _fused_requests(
-            req.seeds,
-            lane_params=dict(req.lane_params),
-            policies=names,
-            tcp_params=dict(req.tcp_params),
-            fault_params=dict(req.fault_params),
-        )
+        with profiler.TraceAnnotation("repro.prepare"):
+            reqs = _fused_requests(
+                req.seeds,
+                lane_params=dict(req.lane_params),
+                policies=names,
+                tcp_params=dict(req.tcp_params),
+                fault_params=dict(req.fault_params),
+            )
         results = run_tcp_lanes_fused(
             reqs,
             n_pkts=req.n_packets,

@@ -88,6 +88,7 @@ import numpy as np
 
 from .. import compat
 from ..kernels import ops as kernel_ops
+from . import record
 from .jaxplane import (
     FaultParams,
     LaneParams,
@@ -168,7 +169,12 @@ def tcp_lane_defaults(**kw) -> dict:
 
 
 class TcpLaneResult(NamedTuple):
-    """Per-lane outputs of :func:`run_tcp_lanes`."""
+    """Per-lane outputs of :func:`run_tcp_lanes`.
+
+    ``active_steps`` / ``scan_steps`` count the event-scan steps taken
+    before the lane went quiet and the steps its segment's scan ran,
+    as on :class:`~repro.core.jaxplane.LaneResult`.
+    """
 
     fct: jnp.ndarray  # [lanes, F] flow completion time (inf if unfinished)
     done: jnp.ndarray  # [lanes, F] flow finished within the step budget
@@ -182,6 +188,9 @@ class TcpLaneResult(NamedTuple):
     claimed_popcount: jnp.ndarray  # [lanes] set bits in the claim bitmap
     claimed_prefix: jnp.ndarray  # [lanes] done prefix of that bitmap
     claimed_words: jnp.ndarray  # [lanes, n_words] that bitmap, uint32
+    # -- scan counters (int32; what the engine ran, not what it simulated)
+    active_steps: jnp.ndarray  # [lanes] steps taken before the lane was quiet
+    scan_steps: jnp.ndarray  # [lanes] steps its segment's scan ran (the shard's)
 
 
 def _trailing_ones(x: jnp.ndarray) -> jnp.ndarray:
@@ -913,84 +922,88 @@ def _tcp_core(
     outs = []
     seg_states, seg_steps, seg_consts = [], [], []
     for pol, sack, (lp, tcp, fparams, seeds) in zip(pols, sacks, blocks):
-        lanes = seeds.shape[0]
-        # NIC-side steering is static per flow (RSS hash / shared queue 0)
-        qid_flow = pol.select_queue(jnp.arange(f_cnt, dtype=jnp.int32), w_cnt)
-        qid_flow = jnp.concatenate([qid_flow, jnp.zeros(1, jnp.int32)])
-        if pol.shared:
-            worker_queue = jnp.zeros(w_cnt, dtype=jnp.int32)
-        else:
-            worker_queue = jnp.arange(w_cnt, dtype=jnp.int32)
-        seg_steps.append(
-            functools.partial(
-                _tcp_step,
-                pol,
-                qid_flow=qid_flow,
-                worker_queue=worker_queue,
-                n_flows=f_cnt,
-                max_pkts=max_pkts,
-                n_workers=w_cnt,
-                max_batch=max_batch,
-                tx_budget=tx_budget,
-                sack=sack,
-                send_burst=send_burst,
+        with jax.named_scope(f"seg.{pol.name}"):
+            lanes = seeds.shape[0]
+            # NIC-side steering is static per flow (RSS hash / shared queue 0)
+            qid_flow = pol.select_queue(jnp.arange(f_cnt, dtype=jnp.int32), w_cnt)
+            qid_flow = jnp.concatenate([qid_flow, jnp.zeros(1, jnp.int32)])
+            if pol.shared:
+                worker_queue = jnp.zeros(w_cnt, dtype=jnp.int32)
+            else:
+                worker_queue = jnp.arange(w_cnt, dtype=jnp.int32)
+            seg_steps.append(
+                functools.partial(
+                    _tcp_step,
+                    pol,
+                    qid_flow=qid_flow,
+                    worker_queue=worker_queue,
+                    n_flows=f_cnt,
+                    max_pkts=max_pkts,
+                    n_workers=w_cnt,
+                    max_batch=max_batch,
+                    tx_budget=tx_budget,
+                    sack=sack,
+                    send_burst=send_burst,
+                )
             )
-        )
-        consts = jax.vmap(
-            functools.partial(_tcp_setup, tx_budget=tx_budget, n_steps=s_pad)
-        )(tcp, seeds)
-        # per-lane effective flow sizes: the packet-budget mask lets
-        # one lane carry an elephant/mice mix over the shared layout
-        pb = jnp.maximum(tcp.pkt_budget.astype(jnp.int32), 0)
-        consts["neff"] = jnp.minimum(n_pad[None, :], pb[:, None])
-        # per-worker fault axes [lanes, W]: crash horizon + service
-        # slowdown (crash_t=+inf / straggler=1.0 on fault-free lanes)
-        widx = jnp.arange(w_cnt, dtype=jnp.float32)
-        consts["crash_w"] = jnp.where(
-            widx[None, :] == fparams.crash_worker[:, None],
-            fparams.crash_t[:, None],
-            jnp.inf,
-        ).astype(jnp.float32)
-        consts["slow_w"] = jnp.where(
-            widx[None, :] == fparams.straggler_worker[:, None],
-            fparams.straggler[:, None],
-            1.0,
-        ).astype(jnp.float32)
-        seg_consts.append(consts)
-        seg_states.append(
-            _tcp_state0(
-                lanes,
-                tcp,
-                t_start,
-                f_cnt,
-                max_pkts,
-                w_cnt,
-                max_batch,
-                tx_budget,
-                sack,
-                send_burst,
+            consts = jax.vmap(
+                functools.partial(_tcp_setup, tx_budget=tx_budget, n_steps=s_pad)
+            )(tcp, seeds)
+            # per-lane effective flow sizes: the packet-budget mask lets
+            # one lane carry an elephant/mice mix over the shared layout
+            pb = jnp.maximum(tcp.pkt_budget.astype(jnp.int32), 0)
+            consts["neff"] = jnp.minimum(n_pad[None, :], pb[:, None])
+            # per-worker fault axes [lanes, W]: crash horizon + service
+            # slowdown (crash_t=+inf / straggler=1.0 on fault-free lanes)
+            widx = jnp.arange(w_cnt, dtype=jnp.float32)
+            consts["crash_w"] = jnp.where(
+                widx[None, :] == fparams.crash_worker[:, None],
+                fparams.crash_t[:, None],
+                jnp.inf,
+            ).astype(jnp.float32)
+            consts["slow_w"] = jnp.where(
+                widx[None, :] == fparams.straggler_worker[:, None],
+                fparams.straggler[:, None],
+                1.0,
+            ).astype(jnp.float32)
+            seg_consts.append(consts)
+            seg_states.append(
+                _tcp_state0(
+                    lanes,
+                    tcp,
+                    t_start,
+                    f_cnt,
+                    max_pkts,
+                    w_cnt,
+                    max_batch,
+                    tx_budget,
+                    sack,
+                    send_burst,
+                )
             )
-        )
 
-    def done_fn(st):
-        return jnp.all(st["quiet"])
+    def lane_done(st):
+        return st["quiet"]
 
+    finals = []
     if engine == "reference":
-        for (lp, tcp, _, _), st0, step, consts in zip(
-            blocks, seg_states, seg_steps, seg_consts
+        for pol, (lp, tcp, _, _), st0, step, consts in zip(
+            pols, blocks, seg_states, seg_steps, seg_consts
         ):
 
             def one_lane(lp_l, tcp_l, c_l, st_l, step=step):
-                def body(s, x):
-                    return step(lp_l, tcp_l, c_l, st=s, xs=x)
+                def body(carry, x):
+                    s, active = carry
+                    active = active + (~lane_done(s)).astype(jnp.int32)
+                    return (step(lp_l, tcp_l, c_l, st=s, xs=x)[0], active), None
 
-                st, _ = jax.lax.scan(body, st_l, (c_l["u"], c_l["stalls"]))
-                return st
+                xs = (c_l["u"], c_l["stalls"])
+                (st, active), _ = jax.lax.scan(body, (st_l, jnp.int32(0)), xs)
+                return st, active
 
-            st = jax.vmap(one_lane)(lp, tcp, consts, st0)
-            outs.append(
-                _tcp_outputs(st, consts, t_start, f_cnt, max_pkts, tx_budget)
-            )
+            with jax.named_scope(f"seg.{pol.name}"), jax.named_scope("scan"):
+                st, active = jax.vmap(one_lane)(lp, tcp, consts, st0)
+            finals.append((st, active, jnp.full_like(active, s_pad)))
     elif engine == "compacted":
         # one specialized chunked scan PER policy segment, all inside
         # the one jitted call: each segment's lanes stop paying for the
@@ -998,8 +1011,8 @@ def _tcp_core(
         # compiles without the untaken policies' branches (a per-lane
         # flag dispatch was measured slower than static segmentation
         # here — the step is compute-bound at sweep lane counts)
-        for (lp, tcp, _, _), st0, step, consts in zip(
-            blocks, seg_states, seg_steps, seg_consts
+        for pol, (lp, tcp, _, _), st0, step, consts in zip(
+            pols, blocks, seg_states, seg_steps, seg_consts
         ):
 
             def body(carry, x, step=step, lp=lp, tcp=tcp, consts=consts):
@@ -1008,14 +1021,19 @@ def _tcp_core(
 
                 return jax.vmap(one)(lp, tcp, consts, carry, x[0], x[1]), ()
 
-            st, _ = _chunked_scan(
-                body, st0, (consts["u"].T, consts["stalls"].T), done_fn, chunk
-            )
-            outs.append(
-                _tcp_outputs(st, consts, t_start, f_cnt, max_pkts, tx_budget)
-            )
+            with jax.named_scope(f"seg.{pol.name}"):
+                xs = (consts["u"].T, consts["stalls"].T)
+                with jax.named_scope("scan"):
+                    st, _, active, scanned = _chunked_scan(
+                        body, st0, xs, lane_done, chunk
+                    )
+            finals.append((st, active, scanned))
     else:
         raise ValueError(f"unknown engine {engine!r}")
+    for pol, consts, (st, active, scanned) in zip(pols, seg_consts, finals):
+        with jax.named_scope(f"seg.{pol.name}"), jax.named_scope("post_scan"):
+            out = _tcp_outputs(st, consts, t_start, f_cnt, max_pkts, tx_budget)
+        outs.append(dict(out, active_steps=active, scan_steps=scanned))
     return tuple(outs)
 
 
@@ -1089,6 +1107,8 @@ def _run_tcp_fused_impl(
             claimed_popcount=o["popcount"],
             claimed_prefix=o["prefix"],
             claimed_words=o["words"],
+            active_steps=o["active_steps"],
+            scan_steps=o["scan_steps"],
         )
         for o in core(blocks)
     )
@@ -1149,11 +1169,37 @@ def run_tcp_lanes_fused(
     a multiple of ``chunk`` so the quiesce short-circuit can skip whole
     chunks; flows that do not finish within them report ``done=False``
     and an infinite ``fct``.  ``shards`` / ``timings`` behave like
-    :func:`repro.core.jaxplane.run_lanes_fused`.
+    :func:`repro.core.jaxplane.run_lanes_fused`, and so do its
+    profiler spans ``repro.prepare`` / ``repro.dispatch`` and its entry
+    in :mod:`repro.core.record`.
     """
     requests = list(requests)
     if not requests:
         raise ValueError("run_tcp_lanes_fused: empty request list")
+    record.install()
+    with jax.profiler.TraceAnnotation("repro.prepare"):
+        args, static, orig_lanes = _tcp_fused_args(
+            requests, n_pkts, t_start, tx_budget, n_steps, chunk, shards
+        )
+    static.update(
+        n_workers=n_workers,
+        max_batch=max_batch,
+        engine=engine,
+        prefix_impl=prefix_impl,
+        prefix_interpret=prefix_interpret,
+    )
+    fn = _tcp_fused_jit(jax.default_backend() != "cpu")
+    with jax.profiler.TraceAnnotation("repro.dispatch"):
+        outs = _call_fused(fn, args, static, timings)
+    return [
+        jax.tree_util.tree_map(lambda a: a[:lanes], res)
+        for res, lanes in zip(outs, orig_lanes)
+    ]
+
+
+def _tcp_fused_args(requests, n_pkts, t_start, tx_budget, n_steps, chunk, shards):
+    """The lane blocks and flow layout of the fused call, its shape
+    statics and each request's lane count (before padding)."""
     n_arr = np.atleast_1d(np.asarray(n_pkts, dtype=np.int32))
     f_cnt = int(n_arr.shape[0])
     max_pkts = int(n_arr.max())
@@ -1216,31 +1262,19 @@ def run_tcp_lanes_fused(
             f"send_burst must agree across fused requests, got {sorted(sb_seen)}"
         )
     send_burst = sb_seen.pop() if sb_seen else 32
-    donate = jax.default_backend() != "cpu"
-    fn = _tcp_fused_jit(donate)
     static = dict(
         pols=tuple(pols),
         n_flows=f_cnt,
         max_pkts=max_pkts,
-        n_workers=n_workers,
-        max_batch=max_batch,
         tx_budget=int(tx_budget),
         s_pad=s_pad,
         chunk=chunk,
         n_shards=n_shards,
-        engine=engine,
         sacks=tuple(sacks),
         send_burst=send_burst,
-        prefix_impl=prefix_impl,
-        prefix_interpret=prefix_interpret,
     )
-    blocks = tuple(blocks)
-    args = (blocks, jnp.asarray(n_arr), jnp.asarray(t_start))
-    outs = _call_fused(fn, args, static, timings)
-    return [
-        jax.tree_util.tree_map(lambda a: a[:lanes], res)
-        for res, lanes in zip(outs, orig_lanes)
-    ]
+    args = (tuple(blocks), jnp.asarray(n_arr), jnp.asarray(t_start))
+    return args, static, orig_lanes
 
 
 def run_tcp_lanes(

@@ -202,6 +202,9 @@ def test_serving_compacted_matches_reference():
         for f in LaneResult._fields:
             a = np.asarray(getattr(compacted[name], f))
             b = np.asarray(getattr(reference[name], f))
+            if f == "scan_steps":  # the reference engine scans every step
+                assert (a <= b).all(), name
+                continue
             assert np.array_equal(a, b, equal_nan=True), (name, f)
 
 
