@@ -69,7 +69,7 @@ Model semantics (matching the DES plane's dynamics, not its RNG stream
 are pre-drawn per lane exactly like the scenario layers pre-draw them;
 state per lane is per-queue claim pointers, per-worker free times and a
 lock horizon (``locked`` only); ``hybrid`` steals couple queues through
-instantaneous backlogs (``searchsorted`` at the claim instant).
+instantaneous backlogs (arrivals counted at the claim instant).
 Latency percentiles, the RFC-4737 Type-P-Reordered ratio / max
 distance, and the exactly-once check (claim-bitmap popcount == done
 prefix == items, via :func:`repro.kernels.ops.done_prefix_packed`) all
@@ -487,15 +487,28 @@ def queue_heads(q_arr, qptr):
     return q_arr[jnp.arange(w), jnp.minimum(qptr, pad)]
 
 
+def row_arrived(row, t):
+    """Entries <= ``t`` in one sorted row: ``searchsorted(side="right")``.
+
+    One compare against ``t`` and a sum, so the row is read once.  The
+    default ``method="scan"`` is a binary search of ceil(log2(n + 1))
+    levels, and under ``vmap`` each level's one-element gather reads
+    the whole batched row: 11 passes over a 2001-long row for one
+    integer.  The integers are the search's, whose sort order puts NaN
+    above +inf: a NaN ``t`` counts the whole row, and any other ``t``
+    counts by IEEE ``<=`` (ties, +inf padding and signed zeros alike).
+    """
+    below = jnp.sum(row <= t, dtype=jnp.int32)
+    return jnp.where(jnp.isnan(t), jnp.int32(row.shape[-1]), below)
+
+
 def rows_arrived(q_arr, t0):
     """Arrivals <= ``t0`` in every sorted (+inf padded) queue row.
 
-    ``searchsorted`` per row — O(W log n) where the pre-compaction
-    engines paid an O(W n) masked sum per claim.  Identical integer
-    results (rows are sorted with +inf padding).
+    :func:`row_arrived` per row: one pass over the ``[W, n+1]`` rows.
+    Both lane engines count every queue's arrivals with it.
     """
-    count = jax.vmap(lambda row: jnp.searchsorted(row, t0, side="right"))
-    return count(q_arr).astype(jnp.int32)
+    return jax.vmap(row_arrived, in_axes=(0, None))(q_arr, t0)
 
 
 def steal_choice(q_arr, qptr, own, t0):
@@ -852,8 +865,7 @@ def _claim_step(
         backlog = backlog_q[q]
     elif pol.shared:
         q = jnp.int32(0)
-        n_arrived = jnp.searchsorted(q_arr[0], t0, side="right")
-        backlog = n_arrived.astype(jnp.int32) - st.qptr[0]
+        backlog = row_arrived(q_arr[0], t0) - st.qptr[0]
     else:
         # own queue when it is claimable at t0, else the first claimable
         # dead peer's queue (the failover wake-up above guarantees one)
@@ -906,9 +918,7 @@ def _claim_step(
     slow = slow_w[w]
     c = crash_w[w]
     svc_budget = base + (c - t1) / slow
-    k_eff = jnp.searchsorted(cumsvc[q], svc_budget, side="right").astype(
-        jnp.int32
-    ) - ptr_s
+    k_eff = row_arrived(cumsvc[q], svc_budget) - ptr_s
     k_eff = jnp.where(active, jnp.clip(k_eff, 0, k), 0).astype(jnp.int32)
     crashed = active & (k_eff < k)
     last = cumsvc[q, jnp.clip(ptr_s + k_eff - 1, 0, n - 1)]
