@@ -788,6 +788,7 @@ def _claim_step(
     the circuit breaker (``breaker_age``) and the latency-reactive
     autoscale gate (``scale_latency``); at the defaults neither branch
     exists in the graph, so control-free lanes stay bit-identical.
+    The serving branches sit under the name scope ``serve``.
     """
     w_count, n = cumsvc.shape
     crash_w, slow_w, lease = flt
@@ -815,39 +816,40 @@ def _claim_step(
     if pol.uses_lock:
         t_cand = jnp.maximum(t_cand, st.lock_t)
     if serving:
-        # Autoscale wake gate: worker w >= base_workers may not claim
-        # before the ((w - base + 1) * scale_backlog)-th unclaimed
-        # arrival of its wake queue exists — "add a worker per
-        # scale_backlog of standing backlog", stated as a wake time so
-        # the gate dissolves exactly as the claim pointer advances.
-        # base_workers = +inf makes ``scaled`` all-false and the gate
-        # a max with -inf: the identity.
-        widx_f = jnp.arange(w_count, dtype=jnp.float32)
-        scaled = widx_f >= sparams.base_workers
-        thr_raw = (widx_f - sparams.base_workers + 1.0) * jnp.maximum(
-            sparams.scale_backlog, 1.0
-        )
-        thr_i = jnp.where(scaled, jnp.clip(thr_raw, 1.0, 2.0**30), 1.0).astype(
-            jnp.int32
-        )
-        if pol.shared:
-            qsel = jnp.zeros((w_count,), jnp.int32)
-        else:
-            qsel = jnp.arange(w_count, dtype=jnp.int32)
-        gate_idx = jnp.clip(st.qptr[qsel] + thr_i - 1, 0, n)
-        t_scale = jnp.where(scaled, q_arr[qsel, gate_idx], -jnp.inf)
-        if math.isfinite(ov.scale_latency):
-            # latency-reactive autoscale: scaled workers wake on the
-            # MEASURED p99 sojourn estimate crossing scale_latency, not
-            # on queue length.  The estimate lives in the carry, so the
-            # gate re-evaluates every step: workers park again once the
-            # estimate decays below the threshold (hysteresis comes
-            # from the asymmetric quantile update below).
-            hot = st.lat_est > ov.scale_latency
-            t_scale = jnp.where(
-                scaled, jnp.where(hot, -jnp.inf, jnp.inf), -jnp.inf
+        with jax.named_scope("serve"):
+            # Autoscale wake gate: worker w >= base_workers may not claim
+            # before the ((w - base + 1) * scale_backlog)-th unclaimed
+            # arrival of its wake queue exists — "add a worker per
+            # scale_backlog of standing backlog", stated as a wake time so
+            # the gate dissolves exactly as the claim pointer advances.
+            # base_workers = +inf makes ``scaled`` all-false and the gate
+            # a max with -inf: the identity.
+            widx_f = jnp.arange(w_count, dtype=jnp.float32)
+            scaled = widx_f >= sparams.base_workers
+            thr_raw = (widx_f - sparams.base_workers + 1.0) * jnp.maximum(
+                sparams.scale_backlog, 1.0
             )
-        t_cand = jnp.maximum(t_cand, t_scale)
+            thr_i = jnp.where(scaled, jnp.clip(thr_raw, 1.0, 2.0**30), 1.0).astype(
+                jnp.int32
+            )
+            if pol.shared:
+                qsel = jnp.zeros((w_count,), jnp.int32)
+            else:
+                qsel = jnp.arange(w_count, dtype=jnp.int32)
+            gate_idx = jnp.clip(st.qptr[qsel] + thr_i - 1, 0, n)
+            t_scale = jnp.where(scaled, q_arr[qsel, gate_idx], -jnp.inf)
+            if math.isfinite(ov.scale_latency):
+                # latency-reactive autoscale: scaled workers wake on the
+                # MEASURED p99 sojourn estimate crossing scale_latency, not
+                # on queue length.  The estimate lives in the carry, so the
+                # gate re-evaluates every step: workers park again once the
+                # estimate decays below the threshold (hysteresis comes
+                # from the asymmetric quantile update below).
+                hot = st.lat_est > ov.scale_latency
+                t_scale = jnp.where(
+                    scaled, jnp.where(hot, -jnp.inf, jnp.inf), -jnp.inf
+                )
+            t_cand = jnp.maximum(t_cand, t_scale)
     # dead-worker mask: a worker whose next feasible claim would start
     # at/after its crash time never claims again (crash-between-claims)
     t_cand = jnp.where(t_cand >= crash_w, jnp.inf, t_cand)
@@ -876,30 +878,31 @@ def _claim_step(
         q = jnp.where(has[w], w, jnp.argmax(has)).astype(jnp.int32)
         backlog = backlog_q[q]
     if serving:
-        # Shed-at-claim admission: before serving, the claiming worker
-        # drops up to max_batch over-limit requests from the queue head
-        # (a real driver's dequeue-side drop still sets the done bit,
-        # so shed items stay in the claim bitmap).  admit_limit = +inf
-        # makes excess exactly 0.0: the identity.
-        excess = jnp.maximum(
-            backlog.astype(jnp.float32) - sparams.admit_limit, 0.0
-        )
-        shed = jnp.where(
-            active, jnp.minimum(excess, float(mb)).astype(jnp.int32), 0
-        )
-        if math.isfinite(ov.breaker_age):
-            # circuit breaker (brownout): when the queue head has aged
-            # past breaker_age the whole claim is shed instead of
-            # served — bounded-staleness service: work that would
-            # expire anyway is dropped cheaply at the head, up to
-            # max_batch per claim, keeping the shed span within the
-            # claim-record window.
-            head_age = t0 - q_arr[q, st.qptr[q]]
-            tripped = active & (backlog > 0) & (head_age > ov.breaker_age)
-            shed = jnp.where(tripped, jnp.minimum(backlog, mb), shed)
-        else:
-            tripped = jnp.zeros((), bool)
-        backlog = backlog - shed
+        with jax.named_scope("serve"):
+            # Shed-at-claim admission: before serving, the claiming worker
+            # drops up to max_batch over-limit requests from the queue head
+            # (a real driver's dequeue-side drop still sets the done bit,
+            # so shed items stay in the claim bitmap).  admit_limit = +inf
+            # makes excess exactly 0.0: the identity.
+            excess = jnp.maximum(
+                backlog.astype(jnp.float32) - sparams.admit_limit, 0.0
+            )
+            shed = jnp.where(
+                active, jnp.minimum(excess, float(mb)).astype(jnp.int32), 0
+            )
+            if math.isfinite(ov.breaker_age):
+                # circuit breaker (brownout): when the queue head has aged
+                # past breaker_age the whole claim is shed instead of
+                # served — bounded-staleness service: work that would
+                # expire anyway is dropped cheaply at the head, up to
+                # max_batch per claim, keeping the shed span within the
+                # claim-record window.
+                head_age = t0 - q_arr[q, st.qptr[q]]
+                tripped = active & (backlog > 0) & (head_age > ov.breaker_age)
+                shed = jnp.where(tripped, jnp.minimum(backlog, mb), shed)
+            else:
+                tripped = jnp.zeros((), bool)
+            backlog = backlog - shed
     else:
         shed = jnp.zeros((), jnp.int32)
         tripped = jnp.zeros((), bool)
@@ -945,18 +948,19 @@ def _claim_step(
     )
     will_reclaim = crashed & jnp.isfinite(lease_v)
     if serving and math.isfinite(ov.scale_latency):
-        # Robbins-Monro p99 tracker fed from claim completions: the
-        # sample is the batch's max sojourn (its first served rank has
-        # the earliest arrival).  est += lr * (0.99 - I[s <= est])
-        # converges to the 0.99-quantile; the asymmetry (big up-steps,
-        # small down-steps) doubles as scale-down hysteresis.
-        samp_ok = active & (k_eff > 0)
-        samp = t_end - q_arr[q, ptr_s]
-        lr = jnp.float32(0.25 * ov.scale_latency)
-        step = lr * (jnp.float32(0.99) - (samp <= st.lat_est).astype(jnp.float32))
-        lat_est = jnp.where(
-            samp_ok, jnp.maximum(st.lat_est + step, 0.0), st.lat_est
-        )
+        with jax.named_scope("serve"):
+            # Robbins-Monro p99 tracker fed from claim completions: the
+            # sample is the batch's max sojourn (its first served rank has
+            # the earliest arrival).  est += lr * (0.99 - I[s <= est])
+            # converges to the 0.99-quantile; the asymmetry (big up-steps,
+            # small down-steps) doubles as scale-down hysteresis.
+            samp_ok = active & (k_eff > 0)
+            samp = t_end - q_arr[q, ptr_s]
+            lr = jnp.float32(0.25 * ov.scale_latency)
+            step = lr * (jnp.float32(0.99) - (samp <= st.lat_est).astype(jnp.float32))
+            lat_est = jnp.where(
+                samp_ok, jnp.maximum(st.lat_est + step, 0.0), st.lat_est
+            )
     else:
         lat_est = st.lat_est
     has = (k_eff + shed) > 0 if serving else k_eff > 0
@@ -1328,7 +1332,7 @@ def _sweep_core(
     for pol, ov, (params, traffic, fparams, sparams, seeds) in zip(
         pols, ovs, blocks
     ):
-        with jax.named_scope(f"seg.{pol.name}"):
+        with jax.named_scope(f"seg.{pol.name}"), jax.named_scope("lane_setup"):
             setup = jax.vmap(
                 functools.partial(
                     _lane_setup,
